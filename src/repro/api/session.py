@@ -866,12 +866,9 @@ class CodedSession:
         )
 
     def jit_cache_entries(self) -> int:
-        """Compiled-executable count of the train step (-1: unavailable
-        on this jax).  1 after a run == the zero-recompile invariant."""
-        size_fn = getattr(self.train_step, "_cache_size", None)
-        if callable(size_fn):
-            return int(size_fn())
-        return -1
+        """Compiled-executable count of the train step.  1 after a run
+        == the zero-recompile invariant."""
+        return int(self.train_step._cache_size())
 
     def report(self, first_step: int = 0) -> Dict:
         """The metrics payload the train CLI writes to --metrics-out."""

@@ -44,17 +44,6 @@ _QMAX = {"int8": 127.0, "int4": 7.0, "fp8": 448.0}
 COMPRESSION_MODES = tuple(_QMAX)
 
 
-def fp8_dtype():
-    """The fp8-e4m3 payload dtype (clear error on ancient jax)."""
-    dt = getattr(jnp, "float8_e4m3fn", None)
-    if dt is None:  # pragma: no cover - all CI jax versions have it
-        raise RuntimeError(
-            "grad_compression='fp8' needs jnp.float8_e4m3fn "
-            "(jax >= 0.4.21)"
-        )
-    return dt
-
-
 @dataclasses.dataclass(frozen=True)
 class QuantMeta:
     """Static shape info needed to undo a blockwise quantizer."""
@@ -171,11 +160,10 @@ def quantize_fp8(x, block: int = DEFAULT_BLOCK):
     payload spends its exponent range on the block's dynamic range —
     relative error ~2^-3 per value vs int8's fixed 1/127 absolute grid.
     """
-    dt = fp8_dtype()
     blocks, amax, shape, pad = _blocked(x, block)
     scales = amax / 448.0
     safe = jnp.where(scales > 0, scales, 1.0)
-    q = (blocks / safe[:, None]).astype(dt)
+    q = (blocks / safe[:, None]).astype(jnp.float8_e4m3fn)
     return q.reshape(-1), scales, QuantMeta(
         shape=shape, block=block, pad=pad, mode="fp8")
 

@@ -25,9 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.dist._compat import on_tpu, shard_map
 
 from repro.dist import compression
 from repro.kernels import ops as kernel_ops
@@ -120,7 +119,7 @@ def compressed_coded_psum(
     """
     pod_axis, worker_axis = axes
     if use_pallas is None:
-        use_pallas = on_tpu()
+        use_pallas = kernel_ops.on_tpu()
     lam = jnp.asarray(lam)
 
     def leaf(x, r):
@@ -180,7 +179,7 @@ def make_coded_allreduce(mesh, axes: Tuple[str, str] = (EDGE_AXIS, WORKER_AXIS))
         mesh=mesh,
         in_specs=(P(), P(pod_axis, worker_axis)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     def runner(tree: PyTree, lam) -> PyTree:
@@ -208,7 +207,7 @@ def make_compressed_cross_pod_sum(
     """
     pod_axis, worker_axis = axes
     n_pods = mesh.shape[pod_axis]
-    use_pallas = on_tpu()
+    use_pallas = kernel_ops.on_tpu()
 
     def inner(tree, lam_block):
         lam = lam_block.reshape(())
@@ -234,7 +233,7 @@ def make_compressed_cross_pod_sum(
         mesh=mesh,
         in_specs=(P(), P(pod_axis, worker_axis)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     def runner(tree: PyTree, lam) -> PyTree:

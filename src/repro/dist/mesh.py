@@ -33,10 +33,18 @@ def make_test_mesh(pods: int, data: int, model: int, stages: int = 1):
             f"{have}; set XLA_FLAGS=--xla_force_host_platform_device_count={need}"
         )
     if stages <= 1:
-        return jax.make_mesh((pods, data, model), ("pod", "data", "model"))
-    return jax.make_mesh(
+        return auto_mesh((pods, data, model), ("pod", "data", "model"))
+    return auto_mesh(
         (stages, pods, data, model), ("stage", "pod", "data", "model")
     )
+
+
+def auto_mesh(shape, names):
+    """``jax.make_mesh`` with every axis Auto: the pspec rules place
+    arrays through ``with_sharding_constraint``, which Explicit axes
+    (``jax.make_mesh``'s default in jax 0.9) refuse."""
+    auto = (jax.sharding.AxisType.Auto,) * len(shape)
+    return jax.make_mesh(shape, names, axis_types=auto)
 
 
 def make_serve_mesh(model: int, data: int = 1):

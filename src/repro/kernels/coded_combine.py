@@ -17,8 +17,11 @@ matmul:
 
 Fb = 512 keeps the working set (K·Fb + Rb·K + Rb·Fb) ≪ 16 MB VMEM for
 K ≤ 2048 and is lane-aligned (128); Rb = 8 matches the f32 sublane.
-The kernel is validated in interpret mode on CPU (tests/test_kernels.py)
-and compiled for TPU via the same pallas_call.
+The quantized variants (``coded_combine_q`` / ``_q4`` / ``_f8``) share
+one dequant-combine kernel with a lane-dense row layout (see below).
+Every kernel is validated in interpret mode on CPU (tests/test_kernels.py)
+and compiled for a described v5e chip (tests/test_tpu_compile.py) via
+the same pallas_call.
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 R_BLOCK = 8
 F_BLOCK = 512
@@ -69,17 +73,119 @@ def coded_combine(
     return out[:R, :F]
 
 
-def _combine_q_kernel(c_ref, g_ref, s_ref, o_ref, *, block: int):
-    # c: (Rb, K), g: (K, Fb) int8, s: (K, Fb/block), o: (Rb, Fb)
-    c = c_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    s = s_ref[...]  # (K, nb)
-    K, Fb = g.shape
-    nb = Fb // block
-    g = (g.reshape(K, nb, block) * s[:, :, None]).reshape(K, Fb)
-    o_ref[...] = jnp.dot(
-        c, g, preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
+# ----------------------------------------------------------------------
+# fused dequant combine (compressed cross-pod hop)
+# ----------------------------------------------------------------------
+# Both operands keep a lane-dense layout reached by free reshapes: the
+# payload (K, P) as (K, rows, 128) and the scales (K, nb) as
+# (K, nb / 128, 128).  With ``per`` payload lanes per scale block
+# (block, or block / 2 for packed int4), one grid step takes
+# SCALE_ROWS scale rows = SCALE_ROWS·per payload rows, so every block
+# shape meets the TPU (8, 128) tiling rule.  Each scale row expands to
+# its (per, 128) payload rows with in-vreg lane gathers.  Coefficients
+# ride SMEM as scalars; the combine is a VPU multiply-accumulate into
+# the f32 output block (the hop's R is 1 and K the pod count, far too
+# skinny for the MXU).
+LANES = 128
+SCALE_ROWS = 8  # f32 sublane tile
+
+
+def _gather_lanes(x, idx):
+    return jnp.take_along_axis(x, idx, axis=1, mode="promise_in_bounds")
+
+
+def _expand_scales(srows, per: int):
+    """(Q, 128) scale rows → (Q·per, 128): payload lane (r, j) gets the
+    scale of block (r·128 + j) // per."""
+    u = jax.lax.broadcasted_iota(jnp.int32, (per, LANES), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (per, LANES), 1)
+    idx = (u * LANES + j) // per
+    return jnp.concatenate(
+        [_gather_lanes(jnp.broadcast_to(srows[q:q + 1], (per, LANES)), idx)
+         for q in range(srows.shape[0])],
+        axis=0,
+    )
+
+
+def _interleave(lo, hi):
+    """(TR, 128) even / odd values → (TR, 256) in value order."""
+    j = jax.lax.broadcasted_iota(jnp.int32, lo.shape, 1)
+    even = j % 2 == 0
+    tiles = []
+    for half in range(2):
+        idx = j // 2 + (LANES // 2) * half
+        tiles.append(jnp.where(even, _gather_lanes(lo, idx),
+                               _gather_lanes(hi, idx)))
+    return jnp.concatenate(tiles, axis=1)
+
+
+def _dequant_combine_kernel(c_ref, g_ref, s_ref, o_ref, *, per: int,
+                            int4: bool):
+    # c: SMEM (R, K); g: (K, TR, 128) payload; s: (K, SCALE_ROWS, 128);
+    # o: (R, TR, 128 or 256 for int4) f32
+    R, K = c_ref.shape
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    def over_k(k, carry):
+        s = _expand_scales(s_ref[k], per)
+        if int4:
+            # byte j holds values 2j (low nibble) and 2j+1 (high), both
+            # in the same scale block
+            p = g_ref[k].astype(jnp.int32) & 0xFF
+            lo = (((p & 0xF) ^ 8) - 8).astype(jnp.float32) * s
+            hi = ((((p >> 4) & 0xF) ^ 8) - 8).astype(jnp.float32) * s
+            deq = _interleave(lo, hi)
+        else:
+            deq = g_ref[k].astype(jnp.float32) * s
+
+        def over_r(r, c2):
+            o_ref[r] += c_ref[r, k] * deq
+            return c2
+
+        return jax.lax.fori_loop(0, R, over_r, carry)
+
+    jax.lax.fori_loop(0, K, over_k, 0)
+
+
+def _dequant_combine(coeff, grads_q, scales, block: int, interpret: bool,
+                     int4: bool):
+    R, K = coeff.shape
+    K2, P = grads_q.shape
+    vals = 2 if int4 else 1  # values per payload element
+    per = block // vals
+    if K != K2 or (P * vals) % block or block % vals:
+        raise ValueError(
+            f"coeff {coeff.shape} / payload {grads_q.shape} do not fit "
+            f"block={block}"
+        )
+    if per % 8 or (LANES % per and per % LANES):
+        raise ValueError(
+            f"block={block}: {per} payload lanes per scale must be a "
+            f"multiple of 8 that divides {LANES} or is a multiple of it"
+        )
+    F = P * vals
+    TR = SCALE_ROWS * per
+    rows = -(-P // (TR * LANES)) * TR
+    Pp = rows * LANES
+    # zero pads (payload and scales) contribute exactly 0
+    gp = jnp.pad(grads_q, ((0, 0), (0, Pp - P)))
+    sp = jnp.pad(scales.astype(jnp.float32),
+                 ((0, 0), (0, Pp // per - scales.shape[1])))
+    out = pl.pallas_call(
+        functools.partial(_dequant_combine_kernel, per=per, int4=int4),
+        grid=(rows // TR,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((K, TR, LANES), lambda t: (0, t, 0)),
+            pl.BlockSpec((K, SCALE_ROWS, LANES), lambda t: (0, t, 0)),
+        ],
+        out_specs=pl.BlockSpec((R, TR, LANES * vals), lambda t: (0, t, 0)),
+        out_shape=jax.ShapeDtypeStruct((R, rows, LANES * vals),
+                                       jnp.float32),
+        interpret=interpret,
+    )(coeff.astype(jnp.float32), gp.reshape(K, rows, LANES),
+      sp.reshape(K, -1, LANES))
+    return out.reshape(R, Pp * vals)[:, :F]
 
 
 @functools.partial(
@@ -94,51 +200,11 @@ def coded_combine_q(
 ) -> jnp.ndarray:
     """Fused int8-dequant coded combine (compression path).
 
-    The de-quantization happens in VMEM right before the MXU matmul —
-    HBM only ever sees int8 gradients (4× traffic cut vs f32).
-    F must be a multiple of ``block``; F_BLOCK must too (128 | 512 ✓).
+    The de-quantization happens in VMEM right before the combine — HBM
+    only ever sees int8 gradients (4× traffic cut vs f32).
     """
-    R, K = coeff.shape
-    K2, F = grads_q.shape
-    assert K == K2 and F % block == 0
-    Rp = -(-R // R_BLOCK) * R_BLOCK
-    Fp = -(-F // F_BLOCK) * F_BLOCK
-    nb_blk = F_BLOCK // block
-    cp = jnp.pad(coeff, ((0, Rp - R), (0, 0)))
-    gp = jnp.pad(grads_q, ((0, 0), (0, Fp - F)))
-    sp = jnp.pad(scales, ((0, 0), (0, (Fp - F) // block)))
-    out = pl.pallas_call(
-        functools.partial(_combine_q_kernel, block=block),
-        grid=(Rp // R_BLOCK, Fp // F_BLOCK),
-        in_specs=[
-            pl.BlockSpec((R_BLOCK, K), lambda r, f: (r, 0)),
-            pl.BlockSpec((K, F_BLOCK), lambda r, f: (0, f)),
-            pl.BlockSpec((K, nb_blk), lambda r, f: (0, f)),
-        ],
-        out_specs=pl.BlockSpec((R_BLOCK, F_BLOCK), lambda r, f: (r, f)),
-        out_shape=jax.ShapeDtypeStruct((Rp, Fp), jnp.float32),
-        interpret=interpret,
-    )(cp, gp, sp)
-    return out[:R, :F]
-
-
-def _combine_q4_kernel(c_ref, g_ref, s_ref, o_ref, *, block: int):
-    # c: (Rb, K), g: (K, Fb/2) packed int4 pairs, s: (K, Fb/block),
-    # o: (Rb, Fb).  Nibbles unpack in VMEM — HBM traffic is 0.5 B/value.
-    c = c_ref[...].astype(jnp.float32)
-    p = g_ref[...].astype(jnp.int32) & 0xFF  # unsigned byte view
-    lo = ((p & 0xF) ^ 8) - 8                 # even value: low nibble
-    hi = (((p >> 4) & 0xF) ^ 8) - 8          # odd value: high nibble
-    K, Fb2 = p.shape
-    g = jnp.stack([lo, hi], axis=-1).reshape(K, Fb2 * 2)
-    g = g.astype(jnp.float32)
-    s = s_ref[...]  # (K, nb)
-    Fb = Fb2 * 2
-    nb = Fb // block
-    g = (g.reshape(K, nb, block) * s[:, :, None]).reshape(K, Fb)
-    o_ref[...] = jnp.dot(
-        c, g, preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
+    return _dequant_combine(coeff, grads_q, scales, block, interpret,
+                            int4=False)
 
 
 @functools.partial(
@@ -156,44 +222,10 @@ def coded_combine_q4(
     ``grads_q`` carries two nibbles per byte in
     :func:`repro.dist.compression.pack_int4` layout (value 2i in the
     low nibble of byte i) — 8× less HBM/wire traffic than f32.  The
-    sign-extend + interleave + scale all happen in VMEM.
+    sign-extend + scale + interleave all happen in VMEM.
     """
-    R, K = coeff.shape
-    K2, F2 = grads_q.shape
-    F = F2 * 2
-    assert K == K2 and F % block == 0 and block % 2 == 0
-    Rp = -(-R // R_BLOCK) * R_BLOCK
-    Fp = -(-F // F_BLOCK) * F_BLOCK
-    nb_blk = F_BLOCK // block
-    cp = jnp.pad(coeff, ((0, Rp - R), (0, 0)))
-    gp = jnp.pad(grads_q, ((0, 0), (0, (Fp - F) // 2)))
-    sp = jnp.pad(scales, ((0, 0), (0, (Fp - F) // block)))
-    out = pl.pallas_call(
-        functools.partial(_combine_q4_kernel, block=block),
-        grid=(Rp // R_BLOCK, Fp // F_BLOCK),
-        in_specs=[
-            pl.BlockSpec((R_BLOCK, K), lambda r, f: (r, 0)),
-            pl.BlockSpec((K, F_BLOCK // 2), lambda r, f: (0, f)),
-            pl.BlockSpec((K, nb_blk), lambda r, f: (0, f)),
-        ],
-        out_specs=pl.BlockSpec((R_BLOCK, F_BLOCK), lambda r, f: (r, f)),
-        out_shape=jax.ShapeDtypeStruct((Rp, Fp), jnp.float32),
-        interpret=interpret,
-    )(cp, gp, sp)
-    return out[:R, :F]
-
-
-def _combine_f8_kernel(c_ref, g_ref, s_ref, o_ref, *, block: int):
-    # c: (Rb, K), g: (K, Fb) fp8-e4m3, s: (K, Fb/block), o: (Rb, Fb)
-    c = c_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    s = s_ref[...]
-    K, Fb = g.shape
-    nb = Fb // block
-    g = (g.reshape(K, nb, block) * s[:, :, None]).reshape(K, Fb)
-    o_ref[...] = jnp.dot(
-        c, g, preferred_element_type=jnp.float32
-    ).astype(o_ref.dtype)
+    return _dequant_combine(coeff, grads_q, scales, block, interpret,
+                            int4=True)
 
 
 @functools.partial(
@@ -211,27 +243,7 @@ def coded_combine_f8(
     Identical tiling to :func:`coded_combine_q`, but the payload is a
     blockwise-scaled float8 — same 4× traffic cut as int8 with relative
     (rather than fixed-grid) per-value precision.  The f32 upcast
-    happens in VMEM right before the MXU matmul.
+    happens in VMEM.
     """
-    R, K = coeff.shape
-    K2, F = grads_q.shape
-    assert K == K2 and F % block == 0
-    Rp = -(-R // R_BLOCK) * R_BLOCK
-    Fp = -(-F // F_BLOCK) * F_BLOCK
-    nb_blk = F_BLOCK // block
-    cp = jnp.pad(coeff, ((0, Rp - R), (0, 0)))
-    gp = jnp.pad(grads_q, ((0, 0), (0, Fp - F)))
-    sp = jnp.pad(scales, ((0, 0), (0, (Fp - F) // block)))
-    out = pl.pallas_call(
-        functools.partial(_combine_f8_kernel, block=block),
-        grid=(Rp // R_BLOCK, Fp // F_BLOCK),
-        in_specs=[
-            pl.BlockSpec((R_BLOCK, K), lambda r, f: (r, 0)),
-            pl.BlockSpec((K, F_BLOCK), lambda r, f: (0, f)),
-            pl.BlockSpec((K, nb_blk), lambda r, f: (0, f)),
-        ],
-        out_specs=pl.BlockSpec((R_BLOCK, F_BLOCK), lambda r, f: (r, f)),
-        out_shape=jax.ShapeDtypeStruct((Rp, Fp), jnp.float32),
-        interpret=interpret,
-    )(cp, gp, sp)
-    return out[:R, :F]
+    return _dequant_combine(coeff, grads_q, scales, block, interpret,
+                            int4=False)

@@ -28,13 +28,9 @@ def on_tpu() -> bool:
     """True iff the default jax backend is a real TPU.
 
     The one place the ``use_pallas`` defaults come from (kernels run
-    compiled on TPU, interpret-mode elsewhere).  ``dist._compat``
-    re-exports this for the layers above kernels.
+    compiled on TPU, interpret-mode elsewhere).
     """
     return jax.default_backend() == "tpu"
-
-
-_on_tpu = on_tpu  # old private name, kept for stragglers
 
 
 def combine(coeff, grads, use_pallas: bool = True):

@@ -21,11 +21,13 @@ import argparse
 import json
 
 from repro.api.aot import HBM_BW, LINK_BW, PEAK_FLOPS, run_cell  # noqa: F401
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import SHAPES
 from repro.configs.registry import ARCH_IDS
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(SHAPES))

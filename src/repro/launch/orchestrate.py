@@ -26,6 +26,7 @@ import json
 import sys
 
 from repro.api import CodedCluster, CodedSession, planner_for_scheme
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.orchestrator import (HeartbeatConfig, InjectionSchedule,
                                 MetricsSink, Orchestrator,
@@ -43,6 +44,7 @@ def _parse_schedule(spec: str, topo, steps: int, seed: int):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--arch", default="llama3-8b", choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true",
@@ -136,11 +138,7 @@ def main(argv=None):
     failed = False
     if args.expect_zero_recompile:
         entries = summary["jit_cache_entries"]
-        if entries == -1:
-            print("[orchestrate] WARNING: jit cache size unavailable "
-                  "on this jax; zero-recompile check skipped",
-                  file=sys.stderr)
-        elif entries != 1:
+        if entries != 1:
             print(f"[orchestrate] FAIL: expected exactly 1 compiled "
                   f"train executable, found {entries}", file=sys.stderr)
             failed = True

@@ -28,14 +28,17 @@ import jax
 
 from repro.api import CodedSession
 from repro.api.serving import generate, prefill_into_cache  # noqa: F401
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.models import transformer as tf
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b", choices=list(ARCH_IDS))
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the reduced config (CPU-runnable)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=32)

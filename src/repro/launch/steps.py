@@ -258,7 +258,7 @@ def _make_dist_train_step(
 
     from repro.dist import grad_sync
     from repro.dist import sharding as shard_lib
-    from repro.dist._compat import shard_map
+    from jax import shard_map
 
     if optimizer is None:
         optimizer = make_optimizer(default_optimizer_name(cfg, tcfg))
@@ -540,7 +540,7 @@ def _make_dist_train_step(
             in_specs=(param_specs, batch_specs,
                       P(pod_axis, data_axis), res_specs),
             out_specs=(param_specs, res_specs, P()) + out_extra,
-            check_rep=False,
+            check_vma=False,
         )
         out = fn(params, batch, lam, residual)
         grads, new_residual, loss = out[0], out[1], out[2]

@@ -43,6 +43,7 @@ from repro.api.session import (  # noqa: F401
     _step_rng,
     build_coded_batch,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.core.topology import Topology
 from repro.launch.steps import _warn_once
@@ -65,6 +66,7 @@ def _make_cluster(kind: str, topo: Topology):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b",
                     choices=list(ARCH_IDS))
@@ -221,12 +223,7 @@ def main(argv=None):
             json.dump(report, f, indent=1)
     if args.expect_zero_recompile:
         cache_entries = report["jit_cache_entries"]
-        if cache_entries == -1:
-            # private jax API unavailable on this version — can't
-            # verify, but absence of the counter is not a recompile
-            print("[train] WARNING: jit cache size unavailable on this "
-                  "jax; zero-recompile check skipped", file=sys.stderr)
-        elif cache_entries != 1:
+        if cache_entries != 1:
             print(f"[train] FAIL: expected exactly 1 jit cache entry "
                   f"(zero recompiles), found {cache_entries}",
                   file=sys.stderr)

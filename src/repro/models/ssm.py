@@ -81,7 +81,10 @@ def _segsum(logdA: jnp.ndarray) -> jnp.ndarray:
     cs = jnp.cumsum(logdA, axis=-1)
     diff = cs[..., :, None] - cs[..., None, :]  # Σ_{j+1..i}
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    return jnp.where(mask, jnp.exp(diff), 0.0)
+    # mask BEFORE the exp: above the diagonal diff is a positive sum
+    # that overflows to inf at long chunks, and where(mask, inf, 0)
+    # has a NaN gradient (0 · inf)
+    return jnp.exp(jnp.where(mask, diff, -jnp.inf))
 
 
 def ssd_chunked(

@@ -160,12 +160,13 @@ def init_params(rng, cfg: ModelConfig) -> PyTree:
     cross = cfg.is_encdec
 
     def stack_layers(rng, count, kind, moe=None):
+        # vmap builds each stacked leaf directly; a per-layer list
+        # stacked afterwards holds every layer twice, more than one
+        # chip has for a 3B-parameter model
         lrngs = jax.random.split(rng, max(count, 1))
-        layers = [
-            _init_layer(lrngs[i], cfg, kind, cross, moe=moe)
-            for i in range(count)
-        ]
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+        init = functools.partial(_init_layer, cfg=cfg, kind=kind,
+                                 cross=cross, moe=moe)
+        return jax.vmap(init)(lrngs)
 
     groups: Dict[str, Any] = {}
     for k in range(P):

@@ -85,7 +85,7 @@ _SCRIPT = textwrap.dedent(
     from jax.sharding import PartitionSpec as P
     from repro.core.hgc import HGCCode
     from repro.core.topology import Tolerance, Topology
-    from repro.dist._compat import shard_map
+    from jax import shard_map
     from repro.dist.grad_sync import coded_weighted_psum, lam_array_from_code
     from repro.dist.mesh import make_test_mesh
 
@@ -109,7 +109,7 @@ _SCRIPT = textwrap.dedent(
         mesh=mesh,
         in_specs=(P("pod", "data", None), P("pod", "data")),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     fn = jax.jit(fn)
 

@@ -48,7 +48,7 @@ _SCRIPT = textwrap.dedent(
     # feed each group its own message by sharding a (pods, data, dim)
     # array and reducing with the coded weights.
     from jax.sharding import PartitionSpec as P
-    from repro.dist._compat import shard_map
+    from jax import shard_map
     from repro.dist.grad_sync import coded_weighted_psum
 
     def inner(msg_block, lam_block):
@@ -61,7 +61,7 @@ _SCRIPT = textwrap.dedent(
         inner, mesh=mesh,
         in_specs=(P("pod", "data", None), P("pod", "data")),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     out = jax.jit(fn)(
         jnp.asarray(msgs.reshape(2, 2, 64)), jnp.asarray(lam)

@@ -105,12 +105,20 @@ def test_rglru_decay_stability():
     assert float(jnp.max(jnp.abs(h))) < 100.0
 
 
-def test_ssd_gradients_finite():
-    xbar, logdA, Bc, Cc = _ssd_inputs(3, 1, 16, 2, 4, 4)
+@pytest.mark.parametrize("Sq,chunk,decay", [
+    (16, 4, 1.0),
+    # mamba2-370m's chunk with its strongest head's decay (A = -16):
+    # the masked upper triangle of the segment sums overflows exp()
+    (256, 256, 16.0),
+])
+def test_ssd_gradients_finite(Sq, chunk, decay):
+    xbar, logdA, Bc, Cc = _ssd_inputs(3, 1, Sq, 2, 4, 4)
+    logdA = logdA * decay
 
-    def loss(xb):
-        y, _ = S.ssd_chunked(xb, logdA, Bc, Cc, chunk=4)
+    def loss(xb, la):
+        y, _ = S.ssd_chunked(xb, la, Bc, Cc, chunk=chunk)
         return jnp.sum(y**2)
 
-    g = jax.grad(loss)(xbar)
-    assert jnp.all(jnp.isfinite(g))
+    gx, ga = jax.grad(loss, argnums=(0, 1))(xbar, logdA)
+    assert jnp.all(jnp.isfinite(gx))
+    assert jnp.all(jnp.isfinite(ga))
