@@ -15,6 +15,7 @@ shardings — the function bodies never change):
 """
 from __future__ import annotations
 
+import itertools
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tf
 
@@ -86,6 +88,10 @@ def make_decode_fn(
     return decode
 
 
+#: request ids of the ``serve.request`` spans, unique in the process
+_request_ids = itertools.count()
+
+
 def generate_tokens(
     params,
     cfg: ModelConfig,
@@ -98,24 +104,37 @@ def generate_tokens(
     greedy: bool = True,
     seed: int = 0,
 ) -> np.ndarray:
-    """The generation loop over prebuilt (possibly sharded) step fns."""
-    if cfg.is_encdec:
-        logits, cache = prefill_fn(params, prompt, enc_frames)
-    else:
-        logits, cache = prefill_fn(params, prompt)
-    rng = jax.random.PRNGKey(seed)
-    out = []
-    tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-    for _ in range(gen_len):
-        out.append(np.asarray(tok))
-        logits, cache = decode_fn(params, tok, cache)
-        if greedy:
+    """The generation loop over prebuilt (possibly sharded) step fns.
+
+    Host spans: one ``serve.request`` per call, and inside it
+    ``serve.prefill`` (with the first token's argmax), then per token
+    ``serve.token_sync`` (the wait for the token), ``serve.decode`` and
+    ``serve.sample``, each with the token's index."""
+    B, S = np.shape(prompt)
+    with tracing.span("serve.request", request=next(_request_ids),
+                      batch=B, prompt_len=S):
+        with tracing.span("serve.prefill"):
+            if cfg.is_encdec:
+                logits, cache = prefill_fn(params, prompt, enc_frames)
+            else:
+                logits, cache = prefill_fn(params, prompt)
             tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        else:
-            rng, k = jax.random.split(rng)
-            tok = jax.random.categorical(k, logits)[:, None].astype(
-                jnp.int32)
-    return np.concatenate(out, axis=1)
+        rng = jax.random.PRNGKey(seed)
+        out = []
+        for i in range(gen_len):
+            with tracing.span("serve.token_sync", token=i):
+                out.append(np.asarray(tok))
+            with tracing.span("serve.decode", token=i):
+                logits, cache = decode_fn(params, tok, cache)
+            with tracing.span("serve.sample", token=i):
+                if greedy:
+                    tok = jnp.argmax(logits, -1)[:, None].astype(
+                        jnp.int32)
+                else:
+                    rng, k = jax.random.split(rng)
+                    tok = jax.random.categorical(k, logits)[:, None] \
+                        .astype(jnp.int32)
+        return np.concatenate(out, axis=1)
 
 
 def prefill_into_cache(

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.api import serving
 from repro.api.cluster import CodedCluster, sample_straggler_pattern
 from repro.api.planner import Planner, get_planner
@@ -53,6 +54,8 @@ from repro.models import transformer as tf
 from repro.optim import make_optimizer
 
 PyTree = Any
+
+tracing.install_gc_spans()
 
 
 class ReplanError(RuntimeError):
@@ -236,6 +239,10 @@ class CodedSession:
         self.log_every = log_every
         self.verbose = verbose
         self.losses: List[float] = []
+        #: steps run, decoded and dispatched (coded) tokens, and workers
+        #: with λ = 0 summed over steps
+        self.counters = dict.fromkeys(
+            ("steps", "useful_tokens", "coded_tokens", "dropped_workers"), 0)
         self._serve_cache: Dict = {}
         self._eval_fn = None
 
@@ -438,11 +445,9 @@ class CodedSession:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.dist import compression as comp_lib
-        from repro.dist import grad_sync
         from repro.dist import sharding as shard_lib
         from repro.dist.mesh import make_test_mesh
 
-        self._grad_sync = grad_sync
         pods, data = topo.n, topo.m[0]
         shard_lib.validate_tp(self.cfg, self.tp)
         if self.seq_shard and (self.tp > 1 or self._seq_shard_explicit):
@@ -564,53 +569,72 @@ class CodedSession:
     def _iteration(self, step: int, force_drop_edge: int = -1,
                    force_drop_step: int = -1, batch=None) -> Dict:
         code, topo = self.code, self.cluster.topo
-        fast_e, fast_w, t_iter, wt = sample_straggler_pattern(
-            _step_rng(self.seed, step), code, self.cluster.params,
-            getattr(code, "load_array", code.load),
-        )
-        if step == force_drop_step and \
-                0 <= force_drop_edge < topo.n and code.tol.s_e > 0:
-            # forced straggler drop: exercise the zero-recompile claim —
-            # only the λ operand changes, never the compiled step
-            fast_e = tuple(
-                i for i in range(topo.n) if i != force_drop_edge
-            )[: topo.n - code.tol.s_e]
-        self.cluster.observe(wt)
-        metrics = self._execute(step, fast_e, fast_w, batch)
+        with tracing.step_span(step) as span:
+            with tracing.span("train.pattern"):
+                fast_e, fast_w, t_iter, wt = sample_straggler_pattern(
+                    _step_rng(self.seed, step), code, self.cluster.params,
+                    getattr(code, "load_array", code.load),
+                )
+                if step == force_drop_step and \
+                        0 <= force_drop_edge < topo.n and code.tol.s_e > 0:
+                    # forced straggler drop: exercise the zero-recompile
+                    # claim — only the λ operand changes, never the
+                    # compiled step
+                    fast_e = tuple(
+                        i for i in range(topo.n) if i != force_drop_edge
+                    )[: topo.n - code.tol.s_e]
+                self.cluster.observe(wt)
+            metrics = self._execute(step, fast_e, fast_w, batch, span)
         metrics["sim_iter_ms"] = t_iter
         metrics["fast_edges"] = fast_e
         return metrics
 
-    def _execute(self, step: int, fast_e, fast_w, batch=None) -> Dict:
+    def _execute(self, step: int, fast_e, fast_w, batch, span) -> Dict:
         """Dispatch ONE compiled train step under a given completion
         set — the shared tail of :meth:`_iteration` (simulated patterns)
-        and :meth:`external_step` (orchestrator-observed patterns)."""
+        and :meth:`external_step` (orchestrator-observed patterns).
+        ``span`` is the step's open ``train.step`` span."""
         code, topo = self.code, self.cluster.topo
+        lam = code.collapsed_weights(fast_e, fast_w)
+        dropped = int(np.count_nonzero(lam == 0))
+        span.set_metadata(dropped_edges=topo.n - len(set(fast_e)),
+                          dropped_workers=dropped)
         if batch is None:
-            batch = self.build_batch(fast_e, fast_w)
-        if self._mesh is None:
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            self.params, self.opt_state, metrics = self.train_step(
-                self.params, self.opt_state, batch, jnp.asarray(step)
-            )
-        else:
-            batch = {
-                k: jax.device_put(jnp.asarray(v), self._batch_sh[k])
-                for k, v in batch.items()
-            }
-            lam_arr = jax.device_put(
-                jnp.asarray(self._grad_sync.lam_array_from_code(
-                    code, fast_e, fast_w, topo.n, topo.m[0]
-                )),
-                self._lam_sh,
-            )
-            (self.params, self.opt_state, self.residual,
-             metrics) = self.train_step(
-                self.params, self.opt_state, batch, lam_arr,
-                self.residual, jnp.asarray(step),
-            )
-        self.losses.append(float(metrics["loss"]))
+            with tracing.span("train.build_batch"):
+                batch = self.build_batch(fast_e, fast_w)
+        rows, seq = np.shape(batch["tokens"])
+        with tracing.span("train.put", rows=rows):
+            if self._mesh is None:
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            else:
+                batch = {
+                    k: jax.device_put(jnp.asarray(v), self._batch_sh[k])
+                    for k, v in batch.items()
+                }
+                lam_arr = jax.device_put(
+                    jnp.asarray(lam.reshape(topo.n, topo.m[0]),
+                                jnp.float32),
+                    self._lam_sh,
+                )
+        with tracing.span("train.dispatch"):
+            if self._mesh is None:
+                self.params, self.opt_state, metrics = self.train_step(
+                    self.params, self.opt_state, batch, jnp.asarray(step)
+                )
+            else:
+                (self.params, self.opt_state, self.residual,
+                 metrics) = self.train_step(
+                    self.params, self.opt_state, batch, lam_arr,
+                    self.residual, jnp.asarray(step),
+                )
+        with tracing.span("train.loss_sync"):
+            self.losses.append(float(metrics["loss"]))
         self._step = step + 1
+        c = self.counters
+        c["steps"] += 1
+        c["useful_tokens"] += code.K * self.part_batch * self.seq_len
+        c["coded_tokens"] += rows * seq
+        c["dropped_workers"] += dropped
         return dict(metrics)
 
     def external_step(self, fast_e, fast_w, *, worker_totals=None,
@@ -646,10 +670,13 @@ class CodedSession:
                     f"{len(set(fast_w[i]))} workers; the deployed code "
                     f"needs >= {need_w}"
                 )
-        if worker_totals is not None:
-            self.cluster.observe(worker_totals)
-        metrics = self._execute(self._step, tuple(fast_e),
-                                [tuple(w) for w in fast_w], batch)
+        with tracing.step_span(self._step) as span:
+            if worker_totals is not None:
+                with tracing.span("train.pattern"):
+                    self.cluster.observe(worker_totals)
+            metrics = self._execute(self._step, tuple(fast_e),
+                                    [tuple(w) for w in fast_w], batch,
+                                    span)
         metrics["sim_iter_ms"] = float(sim_iter_ms)
         metrics["fast_edges"] = tuple(fast_e)
         return metrics
@@ -877,6 +904,7 @@ class CodedSession:
             "first_step": first_step,
             "losses": self.losses,
             "jit_cache_entries": self.jit_cache_entries(),
+            "counters": dict(self.counters),
         }
 
     # ------------------------------------------------------------------

@@ -28,6 +28,7 @@ from jax import lax
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
 from repro.dist import compression
 from repro.kernels import ops as kernel_ops
 
@@ -65,6 +66,7 @@ def lam_array_from_code(
 # ----------------------------------------------------------------------
 # in-shard_map collective (call from inside a shard_map region)
 # ----------------------------------------------------------------------
+@tracing.scoped("lambda_decode")
 def coded_weighted_psum(
     tree: PyTree,
     lam,
@@ -89,6 +91,7 @@ def coded_weighted_psum(
     return jax.tree.map(one, tree)
 
 
+@tracing.scoped("codec")
 def compressed_coded_psum(
     tree: PyTree,
     lam,

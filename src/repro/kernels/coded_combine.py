@@ -69,6 +69,7 @@ def coded_combine(
         out_specs=pl.BlockSpec((R_BLOCK, F_BLOCK), lambda r, f: (r, f)),
         out_shape=jax.ShapeDtypeStruct((Rp, Fp), grads.dtype),
         interpret=interpret,
+        name="coded_combine",
     )(cp, gp)
     return out[:R, :F]
 
@@ -183,6 +184,8 @@ def _dequant_combine(coeff, grads_q, scales, block: int, interpret: bool,
         out_shape=jax.ShapeDtypeStruct((R, rows, LANES * vals),
                                        jnp.float32),
         interpret=interpret,
+        name="dequant_combine_" + ("int4" if int4
+                                   else jnp.dtype(grads_q.dtype).name),
     )(coeff.astype(jnp.float32), gp.reshape(K, rows, LANES),
       sp.reshape(K, -1, LANES))
     return out.reshape(R, Pp * vals)[:, :F]

@@ -140,5 +140,6 @@ def decode_attention_fwd(
         out_specs=pl.BlockSpec((1, Gp, Dh), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Kv, Gp, Dh), q.dtype),
         interpret=interpret,
+        name="decode_attention_fwd",
     )(qpos, qf, kf, vf)
     return out[:, :G, :].reshape(B, 1, H, Dh)

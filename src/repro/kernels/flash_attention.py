@@ -108,6 +108,7 @@ def flash_attention_fwd(
         out_specs=pl.BlockSpec((1, bq, Dh), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, Dh), q.dtype),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 

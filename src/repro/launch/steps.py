@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import tracing
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.models import transformer as tf
 from repro.optim import clip_by_global_norm, cosine_schedule, make_optimizer
@@ -33,6 +34,25 @@ ARCH_OPTIMIZER = {
 
 def default_optimizer_name(cfg: ModelConfig, tcfg: TrainConfig) -> str:
     return ARCH_OPTIMIZER.get(cfg.name, tcfg.optimizer)
+
+
+@tracing.scoped("optimizer")
+def _apply_update(optimizer, tcfg: TrainConfig, lr_at, params, opt_state,
+                  grads, step):
+    """Clip, the optimizer update and its apply: ``(params, opt_state,
+    lr, norm of the clipped gradient)``."""
+    if tcfg.grad_clip > 0:
+        grads = clip_by_global_norm(grads, tcfg.grad_clip)
+    lr = lr_at(step)
+    updates, new_state = optimizer.update(
+        grads, opt_state, params, lr, tcfg.weight_decay
+    )
+    new_params = jax.tree.map(lambda p, u: p + u, params, updates)
+    grad_norm = jnp.sqrt(
+        sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+            for g in jax.tree.leaves(grads))
+    )
+    return new_params, new_state, lr, grad_norm
 
 
 def make_train_step(
@@ -120,19 +140,11 @@ def make_train_step(
 
     def train_step(params, opt_state, batch, step):
         grads, metrics = grads_of(params, batch)
-        if tcfg.grad_clip > 0:
-            grads = clip_by_global_norm(grads, tcfg.grad_clip)
-        lr = lr_at(step)
-        updates, new_state = optimizer.update(
-            grads, opt_state, params, lr, tcfg.weight_decay
-        )
-        new_params = jax.tree.map(lambda p, u: p + u, params, updates)
+        new_params, new_state, lr, grad_norm = _apply_update(
+            optimizer, tcfg, lr_at, params, opt_state, grads, step)
         metrics = dict(metrics)
         metrics["lr"] = lr
-        metrics["grad_norm"] = jnp.sqrt(
-            sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree.leaves(grads))
-        )
+        metrics["grad_norm"] = grad_norm
         return new_params, new_state, metrics
 
     train_step.optimizer = optimizer
@@ -544,21 +556,9 @@ def _make_dist_train_step(
         )
         out = fn(params, batch, lam, residual)
         grads, new_residual, loss = out[0], out[1], out[2]
-        if tcfg.grad_clip > 0:
-            grads = clip_by_global_norm(grads, tcfg.grad_clip)
-        lr = lr_at(step)
-        updates, new_state = optimizer.update(
-            grads, opt_state, params, lr, tcfg.weight_decay
-        )
-        new_params = jax.tree.map(lambda p, u: p + u, params, updates)
-        metrics = {
-            "loss": loss,
-            "lr": lr,
-            "grad_norm": jnp.sqrt(
-                sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree.leaves(grads))
-            ),
-        }
+        new_params, new_state, lr, grad_norm = _apply_update(
+            optimizer, tcfg, lr_at, params, opt_state, grads, step)
+        metrics = {"loss": loss, "lr": lr, "grad_norm": grad_norm}
         if cfg.is_moe:
             metrics["aux_loss"] = out[3]
         return new_params, new_state, new_residual, metrics

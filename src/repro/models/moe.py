@@ -19,6 +19,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.dist.sharding import NULL_CTX
 
 
@@ -42,6 +43,7 @@ def init_moe(rng, d: int, ff: int, E: int, n_shared: int, dtype) -> Dict:
     return p
 
 
+@tracing.scoped("moe")
 def moe_ffn(
     params: Dict,
     x: jnp.ndarray,  # (B, S, d)

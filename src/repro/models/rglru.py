@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import tracing
 from repro.dist.sharding import NULL_CTX
 
 _C = 8.0
@@ -112,6 +113,7 @@ def _causal_conv(seq, w, b):
     return out + b
 
 
+@tracing.scoped("rglru")
 def rglru_block_forward(params, x: jnp.ndarray, cfg,
                         ctx=NULL_CTX) -> jnp.ndarray:
     """Full recurrent block (train/prefill). x: (B, S, d).
@@ -147,6 +149,7 @@ def rglru_init_cache(cfg, batch: int, dtype=jnp.float32) -> Dict:
     }
 
 
+@tracing.scoped("rglru")
 def rglru_block_step(params, x1: jnp.ndarray, cache: Dict, cfg):
     """Decode step. x1: (B, 1, d)."""
     gate = jax.nn.gelu(x1 @ params["w_gate"])

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import tracing
 from repro.dist.sharding import NULL_CTX
 
 
@@ -87,6 +88,7 @@ def _segsum(logdA: jnp.ndarray) -> jnp.ndarray:
     return jnp.exp(jnp.where(mask, diff, -jnp.inf))
 
 
+@tracing.scoped("ssd")
 def ssd_chunked(
     xbar: jnp.ndarray,  # (B, S, nh, hd)  = dt · x
     logdA: jnp.ndarray,  # (B, S, nh)      = dt · A  (A < 0)
@@ -170,6 +172,7 @@ def _head_params(params: Dict, nh_local: int, ctx):
     return A_log, D, dt_bias
 
 
+@tracing.scoped("ssm")
 def ssm_forward(
     params: Dict,
     x: jnp.ndarray,  # (B, S, d)
@@ -227,6 +230,7 @@ def ssm_init_cache(cfg, batch: int, dtype=jnp.float32) -> Dict:
     }
 
 
+@tracing.scoped("ssm")
 def ssm_decode_step(
     params: Dict,
     x: jnp.ndarray,  # (B, 1, d)

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import tracing
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import (
     NULL_CTX,
@@ -201,6 +202,7 @@ def _split_heads(x, n, Dh):
     return x.reshape(*x.shape[:-1], n, Dh)
 
 
+@tracing.scoped("attention")
 def _attn_apply(
     p: Dict, x: jnp.ndarray, cfg: ModelConfig, kind: str,
     positions: jnp.ndarray,
@@ -280,6 +282,7 @@ def _attn_apply(
     return out, kv
 
 
+@tracing.scoped("mlp")
 def _mlp_apply(p: Dict, x: jnp.ndarray, cfg: ModelConfig,
                ctx: ShardCtx = NULL_CTX):
     # SP: the column-parallel up-projections want the full sequence
@@ -376,6 +379,7 @@ def _layer_apply(
 # ----------------------------------------------------------------------
 # full forward (train / prefill)
 # ----------------------------------------------------------------------
+@tracing.scoped("weight_cast")
 def cast_params(params: PyTree, cfg: ModelConfig) -> PyTree:
     """One bf16 working copy of the weights (norm scales stay f32).
 
@@ -391,6 +395,7 @@ def cast_params(params: PyTree, cfg: ModelConfig) -> PyTree:
     return jax.tree.map(cast, params)
 
 
+@tracing.scoped("embed")
 def _embed(params, cfg, tokens, ctx: ShardCtx = NULL_CTX):
     # Gathers from a sharded table hit an SPMD-partitioner verifier bug
     # (invalid dynamic-slice in the "last resort" path).  The table is
@@ -415,6 +420,7 @@ def _matmul_f32(x, w, cfg):
     )
 
 
+@tracing.scoped("head_loss")
 def _unembed(params, cfg, x, ctx: ShardCtx = NULL_CTX):
     x = _norm(params["final_norm"], x)
     # SP: the final norm ran on the local seq block; the vocab-parallel
@@ -565,6 +571,7 @@ def _apply_rest(
     return x, rest_caches, aux_total
 
 
+@tracing.scoped("head_loss")
 def _ce_nll(
     logits: jnp.ndarray, targets: jnp.ndarray, cfg: ModelConfig,
     ctx: ShardCtx = NULL_CTX,
@@ -807,6 +814,55 @@ def fill_cross_cache(params: PyTree, cfg: ModelConfig,
     return cache
 
 
+@tracing.scoped("attention")
+def _decode_self_attn(
+    p: Dict, h: jnp.ndarray, kind: str, cfg: ModelConfig,
+    cache_entry: PyTree, pos: jnp.ndarray, use_pallas: bool,
+) -> Tuple[jnp.ndarray, PyTree]:
+    """One token's self-attention against its ring-buffer cache entry;
+    returns (output, updated entry)."""
+    B = h.shape[0]
+    H, Kv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q = _split_heads(h @ p["wq"], H, Dh)
+    k = _split_heads(h @ p["wk"], Kv, Dh)
+    v = _split_heads(h @ p["wv"], Kv, Dh)
+    posb = jnp.full((B, 1), pos)
+    if cfg.mrope_sections:
+        posb = jnp.broadcast_to(posb, (3, B, 1))
+    q = attn_lib.apply_rope(q, posb, cfg.rope_theta, cfg.mrope_sections)
+    k = attn_lib.apply_rope(k, posb, cfg.rope_theta, cfg.mrope_sections)
+    C = cache_entry["k"].shape[1]
+    window = cfg.window if kind == "local" else 0
+    slot = pos % C
+    kc = lax.dynamic_update_slice_in_dim(
+        cache_entry["k"], k.reshape(B, 1, Kv * Dh).astype(
+            cache_entry["k"].dtype), slot, 1)
+    vc = lax.dynamic_update_slice_in_dim(
+        cache_entry["v"], v.reshape(B, 1, Kv * Dh).astype(
+            cache_entry["v"].dtype), slot, 1)
+    if use_pallas:
+        # fused kernel derives the slot-position vector in VMEM from
+        # the ring write pointer (same formula as below)
+        from repro.kernels import ops as kernel_ops
+
+        out = kernel_ops.decode_attention(
+            q, kc.reshape(B, C, Kv, Dh), vc.reshape(B, C, Kv, Dh),
+            pos, window=window, softcap=cfg.logit_softcap,
+        )
+    else:
+        k_pos = attn_lib.ring_slot_positions(
+            C, pos + 1, window if window > 0 else C
+        )
+        out = attn_lib.decode_attention(
+            q, kc.reshape(B, C, Kv, Dh), vc.reshape(B, C, Kv, Dh),
+            pos, k_pos, window=window, softcap=cfg.logit_softcap,
+        )
+    out = out.reshape(B, 1, H * Dh) @ p["wo"]
+    new_entry = dict(cache_entry)
+    new_entry.update({"k": kc, "v": vc})
+    return out, new_entry
+
+
 def _decode_layer(
     p: Dict, x1: jnp.ndarray, kind: str, cfg: ModelConfig,
     cache_entry: PyTree, pos: jnp.ndarray,
@@ -816,43 +872,8 @@ def _decode_layer(
     H, Kv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     h = _norm(p["norm1"], x1)
     if kind in ("global", "local"):
-        q = _split_heads(h @ p["attn"]["wq"], H, Dh)
-        k = _split_heads(h @ p["attn"]["wk"], Kv, Dh)
-        v = _split_heads(h @ p["attn"]["wv"], Kv, Dh)
-        posb = jnp.full((B, 1), pos)
-        if cfg.mrope_sections:
-            posb = jnp.broadcast_to(posb, (3, B, 1))
-        q = attn_lib.apply_rope(q, posb, cfg.rope_theta, cfg.mrope_sections)
-        k = attn_lib.apply_rope(k, posb, cfg.rope_theta, cfg.mrope_sections)
-        C = cache_entry["k"].shape[1]
-        window = cfg.window if kind == "local" else 0
-        slot = pos % C
-        kc = lax.dynamic_update_slice_in_dim(
-            cache_entry["k"], k.reshape(B, 1, Kv * Dh).astype(
-                cache_entry["k"].dtype), slot, 1)
-        vc = lax.dynamic_update_slice_in_dim(
-            cache_entry["v"], v.reshape(B, 1, Kv * Dh).astype(
-                cache_entry["v"].dtype), slot, 1)
-        if use_pallas:
-            # fused kernel derives the slot-position vector in VMEM from
-            # the ring write pointer (same formula as below)
-            from repro.kernels import ops as kernel_ops
-
-            out = kernel_ops.decode_attention(
-                q, kc.reshape(B, C, Kv, Dh), vc.reshape(B, C, Kv, Dh),
-                pos, window=window, softcap=cfg.logit_softcap,
-            )
-        else:
-            k_pos = attn_lib.ring_slot_positions(
-                C, pos + 1, window if window > 0 else C
-            )
-            out = attn_lib.decode_attention(
-                q, kc.reshape(B, C, Kv, Dh), vc.reshape(B, C, Kv, Dh),
-                pos, k_pos, window=window, softcap=cfg.logit_softcap,
-            )
-        out = out.reshape(B, 1, H * Dh) @ p["attn"]["wo"]
-        new_entry = dict(cache_entry)
-        new_entry.update({"k": kc, "v": vc})
+        out, new_entry = _decode_self_attn(p["attn"], h, kind, cfg,
+                                           cache_entry, pos, use_pallas)
     elif kind == "ssm":
         out, new_entry = ssm_lib.ssm_decode_step(p["ssm"], h, cache_entry, cfg)
     elif kind == "recurrent":
@@ -864,16 +885,18 @@ def _decode_layer(
     x1 = x1 + out
     if "xattn" in p and isinstance(cache_entry, dict) and "xk" in cache_entry:
         hx = _norm(p["norm_x"], x1)
-        q = _split_heads(hx @ p["xattn"]["wq"], H, Dh)
-        Ce = cache_entry["xk"].shape[1]
-        out = attn_lib.decode_attention(
-            q,
-            cache_entry["xk"].reshape(B, Ce, Kv, Dh),
-            cache_entry["xv"].reshape(B, Ce, Kv, Dh),
-            jnp.asarray(Ce, jnp.int32),  # attend over the whole encoder
-            jnp.arange(Ce),
-        )
-        x1 = x1 + out.reshape(B, 1, H * Dh) @ p["xattn"]["wo"]
+        with tracing.scope("attention"):
+            q = _split_heads(hx @ p["xattn"]["wq"], H, Dh)
+            Ce = cache_entry["xk"].shape[1]
+            out = attn_lib.decode_attention(
+                q,
+                cache_entry["xk"].reshape(B, Ce, Kv, Dh),
+                cache_entry["xv"].reshape(B, Ce, Kv, Dh),
+                jnp.asarray(Ce, jnp.int32),  # attend over the whole encoder
+                jnp.arange(Ce),
+            )
+            out = out.reshape(B, 1, H * Dh) @ p["xattn"]["wo"]
+        x1 = x1 + out
     if "norm2" in p:
         h = _norm(p["norm2"], x1)
         if "moe" in p:

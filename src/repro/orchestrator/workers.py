@@ -76,7 +76,6 @@ class Result:
     runtime_ms: float        # simulated eq.-31 total (slow-factor applied)
     sent_ms: float           # virtual completion time (the beat stamp)
     partial: np.ndarray      # encoded probe partial  Σ_k coeffs[k]·s_k
-    wall_us: float           # real compute wall time (metrics only)
 
 
 def probe_part_vector(probe_seed: int, k: int, dim: int) -> np.ndarray:
@@ -123,7 +122,6 @@ def _worker_main(flat: int, row: ModelRow, seed: int, inbox, outbox):
         if msg[0] == "stop":
             return
         work: WorkItem = msg[1]
-        t0 = time.perf_counter()
         runtime = draw_runtime_ms(row, flat, work.step, seed, work.D,
                                   work.slow_factor)
         partial = np.zeros(work.probe_dim)
@@ -134,7 +132,6 @@ def _worker_main(flat: int, row: ModelRow, seed: int, inbox, outbox):
         outbox.put(("result", Result(
             flat=flat, step=work.step, runtime_ms=runtime,
             sent_ms=work.clock_ms + runtime, partial=partial,
-            wall_us=(time.perf_counter() - t0) * 1e6,
         )))
 
 
