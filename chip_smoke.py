@@ -42,10 +42,10 @@ DROP_EDGE, DROP_STEP = 1, 3
 TRAIN_LR = 1e-3
 
 SERVE_ARCH = "starcoder2-3b"
-# 20 of the 30 layers: with f32 weights (param_dtype) the full depth
-# needs 17.2 GB for prefill (15.75 GB on a v5e); at 22 layers prefill
-# compiles to 13.9 GB, at 20 to 12.8 GB, which leaves room for the
-# runtime and the cache beside the program
+# 20 of the 30 layers, sized when serving held f32 weights: the full
+# depth then needed 17.2 GB for prefill (15.75 GB on a v5e), 20 layers
+# 12.8 GB.  A serve-only session holds bf16 weights (~6.2 GB at 30
+# layers); the depth stays so the smoke's numbers remain comparable
 SERVE_LAYERS = 20
 SERVE_BATCH, SERVE_PROMPT, SERVE_GEN = 4, 128, 32
 

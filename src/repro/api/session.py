@@ -150,6 +150,11 @@ class CodedSession:
 
     ``cluster=None`` builds a serve-only session (no planning, no data
     streams, no train step) — the serving driver's mode.
+
+    ``cfg.param_dtype`` is the dtype of the weights a session trains
+    (its master copy, cast to ``cfg.dtype`` inside every step).  A
+    serve-only session never updates its weights, so it holds them in
+    the compute dtype ``cfg.dtype``, cast once at construction.
     """
 
     def __init__(
@@ -248,9 +253,12 @@ class CodedSession:
 
         # model state (shared by train and serve paths)
         rng = jax.random.PRNGKey(seed)
-        self.params = tf.init_params(rng, cfg)
-
         if cluster is None:  # serve-only session: no optimizer, no plan
+            # the weights never change, so hold the copy prefill and
+            # decode compute in (their cast_params is then a no-op), made
+            # in one jit so the float32 tree is never held beside it
+            self.params = jax.jit(
+                lambda k: tf.cast_params(tf.init_params(k, cfg), cfg))(rng)
             self.plan = None
             self.code = None
             self.tcfg = None
@@ -260,6 +268,7 @@ class CodedSession:
             self._step = 0
             self._mesh = None
             return
+        self.params = tf.init_params(rng, cfg)
         self._optimizer = make_optimizer(optimizer)
 
         # ---- plan the code ------------------------------------------

@@ -65,6 +65,8 @@ class ModelConfig:
 
     # numerics / structure
     dtype: str = "bfloat16"
+    # dtype of the weights a session trains (cast to ``dtype`` inside
+    # every step); a serve-only session holds ``dtype`` instead
     param_dtype: str = "float32"
     remat: bool = True
     attn_chunk: int = 1024  # kv-chunked attention when seq > this

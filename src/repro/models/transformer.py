@@ -381,9 +381,13 @@ def _layer_apply(
 # ----------------------------------------------------------------------
 @tracing.scoped("weight_cast")
 def cast_params(params: PyTree, cfg: ModelConfig) -> PyTree:
-    """One bf16 working copy of the weights (norm scales stay f32).
+    """The working copy of the weights in the compute dtype.
 
-    No-op when param_dtype == compute dtype (the big-model configs).
+    Every float32 leaf of two or more dims is cast: the matrices, and
+    the per-layer vectors (norm scales, biases, gates) stacked under the
+    layer scan.  Only 1-D leaves, such as the final norm's scale, stay
+    float32.  A no-op on leaves already in another dtype: weights held
+    in the compute dtype (a serve-only session, bf16 master configs).
     """
     tgt = jnp.dtype(cfg.dtype)
 

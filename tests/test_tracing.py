@@ -18,6 +18,7 @@ import pytest
 from repro import tracing
 from repro.api import CodedCluster, CodedSession, planner_for_scheme
 from repro.configs.registry import get_smoke_config
+from repro.models import transformer as tf
 
 _WRAPPED = re.compile(r"^[\w\-]+\((.*)\)$")
 
@@ -72,15 +73,22 @@ def test_train_step_hlo_carries_scopes(arch, want):
                      r'attention)', text)
 
 
-def test_decode_step_hlo_carries_scopes():
-    s = CodedSession(None, get_smoke_config("starcoder2-3b"))
+@pytest.mark.parametrize("weights", ["held", "f32"])
+def test_decode_step_hlo_carries_scopes(weights):
+    """A serve-only session holds its weights in the compute dtype, so
+    its decode casts nothing; float32 weights passed to the same compiled
+    decode are cast under ``weight_cast`` on every call."""
+    cfg = get_smoke_config("starcoder2-3b")
+    s = CodedSession(None, cfg)
+    params = (s.params if weights == "held"
+              else tf.init_params(jax.random.PRNGKey(0), cfg))
     prefill, decode, _ = s._serve_fns(24, False)
-    cache = jax.eval_shape(prefill, s.params,
+    cache = jax.eval_shape(prefill, params,
                            jax.ShapeDtypeStruct((2, 8), jnp.int32))[1]
     tok = jnp.zeros((2, 1), jnp.int32)
-    text = decode.lower(s.params, tok, cache).compile().as_text()
-    assert {"weight_cast", "attention", "embed", "mlp",
-            "head_loss"} <= hlo_scopes(text)
+    scopes = hlo_scopes(decode.lower(params, tok, cache).compile().as_text())
+    assert {"attention", "embed", "mlp", "head_loss"} <= scopes
+    assert ("weight_cast" in scopes) == (weights == "f32")
 
 
 _DIST = textwrap.dedent("""
